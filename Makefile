@@ -11,7 +11,7 @@ fast:          ## tier-1 minus `slow` (distributed / subprocess) tests
 smoke:         ## per-push gate: lint + import + collect + fast unit subset
 	scripts/ci.sh smoke
 
-lint:          ## forbidden-API checks only (jax-0.4.37 quirks)
+lint:          ## forbidden-API checks only (missing packages, trainer syncs)
 	scripts/ci.sh lint
 
 serve-smoke:   ## serving end-to-end + gated serve_* ratios vs baseline

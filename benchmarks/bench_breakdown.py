@@ -62,8 +62,9 @@ def run_overlap():
         f"exposed_over_barrier="
         f"{pipe['exposed_bytes'] / barrier['exposed_bytes']:.4f}")
     # measured WalkStats on 2 virtual devices (subprocess: XLA device count
-    # is process-global, same pattern as the sharded parity tests)
-    env = dict(os.environ,
+    # is process-global, same pattern as the sharded parity tests; pinned
+    # to the CPU, since a parent that has touched JAX holds any accelerator)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in ("src", env.get("PYTHONPATH", "")) if p)
@@ -71,9 +72,8 @@ def run_overlap():
         [sys.executable, "-c", _MEASURED_SCRIPT, SKEW5_SPEC],
         capture_output=True, text=True, env=env, timeout=600)
     if proc.returncode:
-        row("overlap_measured", 0, "subprocess_failed")
-        print(proc.stderr[-2000:], file=sys.stderr)
-        return
+        raise RuntimeError(f"2-device subprocess failed (rc "
+                           f"{proc.returncode}):\n{proc.stderr[-2000:]}")
     line = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("RESULT ")][-1]
     meas = json.loads(line[len("RESULT "):])

@@ -8,7 +8,8 @@
 #   fast            — deselect `slow` (distributed/subprocess/bench-shaped)
 #   smoke           — the per-push gate: forbidden-API lint, import check,
 #                     collect-only, then a fast unit subset (minutes)
-#   lint            — just the forbidden-API checks (jax-0.4.37 quirks)
+#   lint            — just the forbidden-API checks (missing packages,
+#                     host round-trips in the streamed trainer)
 #   serve-smoke     — serving end-to-end: serve_graph --smoke replays a Zipf
 #                     trace, then bench_serve --smoke gates the serve_*
 #                     ratios against the committed baseline
@@ -37,8 +38,7 @@ SMOKE_TESTS=(tests/test_graph.py tests/test_ingest.py tests/test_alias.py
 
 lint() {
   # Forbidden APIs — environment quirks codified so they can't regress
-  # (jax 0.4.37: no jax.shard_map; cost_analysis() returns a list; the
-  # container has no hypothesis and pip install is not permitted).
+  # (the container has no hypothesis and pip install is not permitted).
   local fail=0
   local paths=(src tests benchmarks examples scripts)
 
@@ -46,24 +46,6 @@ lint() {
        "${paths[@]}" --include="*.py"; then
     echo "LINT FAIL: hypothesis is not installed in the CI container;" \
          "use seeded pytest.mark.parametrize sweeps instead" >&2
-    fail=1
-  fi
-
-  # bare jax.shard_map does not exist on jax 0.4.37 — everything must go
-  # through the _shard_map compat shim in core/walk_distributed.py
-  if grep -rn "jax\.shard_map" "${paths[@]}" --include="*.py" \
-       | grep -v "src/repro/core/walk_distributed.py"; then
-    echo "LINT FAIL: bare jax.shard_map (absent on jax 0.4.37); use the" \
-         "_shard_map shim in repro.core.walk_distributed" >&2
-    fail=1
-  fi
-
-  # compiled.cost_analysis() returns a list on jax 0.4.37 — direct
-  # indexing belongs only in the roofline normalizer (cost_dict)
-  if grep -rn "\.cost_analysis()\[" "${paths[@]}" --include="*.py" \
-       | grep -v "src/repro/roofline/analysis.py"; then
-    echo "LINT FAIL: direct cost_analysis()[...] indexing (list on jax" \
-         "0.4.37); normalize via repro.roofline.analysis.cost_dict" >&2
     fail=1
   fi
 

@@ -75,7 +75,7 @@ def sgns_grads(params, batch, backend: str = "jnp"):
     (``repro.kernels.sgns``: ci/po/no read once, three grads written once),
     scatter-add the row grads back into the tables. Same math as autodiff
     (the kernel-vs-autodiff contract is tested in tests/test_kernels.py);
-    interpret mode off-TPU.
+    interpret mode on the CPU backend.
     """
     center, pos, negs = batch["center"], batch["pos"], batch["neg"]
     valid = batch.get("valid")

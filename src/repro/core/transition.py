@@ -20,7 +20,6 @@ from __future__ import annotations
 
 from typing import Dict
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,15 +47,6 @@ def unnormalized_probs(cand_ids: jnp.ndarray, cand_w: jnp.ndarray,
     alpha = jnp.where(is_u, 1.0 / p, jnp.where(common, 1.0, 1.0 / q))
     valid = cand_ids != PAD_ID
     return jnp.where(valid, alpha * cand_w, 0.0)
-
-
-def sample_slot(key: jax.Array, probs: jnp.ndarray) -> jnp.ndarray:
-    """Inverse-CDF draw over an unnormalized prob row; returns slot index."""
-    cum = jnp.cumsum(probs)
-    total = cum[-1]
-    r = jax.random.uniform(key) * total
-    idx = jnp.searchsorted(cum, r, side="right")
-    return jnp.minimum(idx, probs.shape[-1] - 1).astype(jnp.int32)
 
 
 def approx_gap(deg_u: jnp.ndarray, deg_v: jnp.ndarray, w_min_v: jnp.ndarray,

@@ -5,8 +5,8 @@ two ``WalkEngine`` backends:
 * ``"reference"`` — all sampling in plain jnp;
 * ``"fused"``     — the exact 2nd-order draw runs in the Pallas kernel
   (``kernels.node2vec_step`` via the ``kernels.ops`` padding contract),
-  interpret mode off-TPU. Both are this module's ``run_reference`` with a
-  different :class:`~repro.engine.sampler.Sampler`.
+  interpret mode on the CPU backend. Both are this module's
+  ``run_reference`` with a different :class:`~repro.engine.sampler.Sampler`.
 
 The walk is fully vectorized over walkers with a ``lax.scan`` over supersteps
 (one scan iteration == one Pregel superstep; the BSP barrier is implicit in
@@ -158,39 +158,3 @@ def run_reference(pg: PaddedGraph, starts: jnp.ndarray,
     """Single-device backend entry point used by ``WalkEngine``."""
     return _simulate(pg, starts, walker_ids, seed_key, sampler=sampler,
                      length=length)
-
-
-@functools.partial(jax.jit, static_argnames=("sampler", "length"))
-def run_fused_persistent(pg: PaddedGraph, starts: jnp.ndarray,
-                         walker_ids: jnp.ndarray, seed_key: jax.Array,
-                         sampler: Sampler, length: int) -> jnp.ndarray:
-    """Fused backend with ``WalkPlan.pipeline``: one Pallas call runs every
-    2nd-order superstep, carrying the prev-neighbor rows in VMEM instead of
-    re-reading a [W, DP] block from HBM per step (``kernels.node2vec_walk``).
-
-    Requires exact mode + FN-Base layout (empty hot set; the engine gates
-    this). Step 0 (first-order alias draw) and the per-(walker, step)
-    uniforms stay on the host — the RNG contract is a pure function of
-    (walker, step), so walks are bit-identical to ``run_reference``.
-    """
-    from repro.kernels.ops import node2vec_walk_op
-
-    k0 = jax.vmap(lambda i: walker_key(seed_key, i, 0))(walker_ids)
-    ids0, _, ap0, ai0, _ = _batched_rows(pg, starts)
-    deg0 = pg.deg[starts]
-    slot0 = first_order_slots(k0, ap0, ai0, deg0)
-    nxt0 = jnp.take_along_axis(ids0, slot0[:, None], axis=1)[:, 0]
-    v1 = jnp.where(deg0 > 0, nxt0, starts)
-    if length == 1:
-        return v1[:, None]
-
-    def step_rand(i):
-        def at(s):
-            k = walker_key(seed_key, i, s)
-            return jax.random.uniform(jax.random.split(k)[0])
-        return jax.vmap(at)(jnp.arange(1, length, dtype=jnp.int32))
-
-    rand = jax.vmap(step_rand)(walker_ids)            # [W, length-1]
-    tail = node2vec_walk_op(pg.adj, pg.wgt, pg.deg, starts, v1, rand,
-                            sampler.p, sampler.q)
-    return jnp.concatenate([v1[:, None], tail], axis=1)

@@ -60,36 +60,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph import PAD_ID, PaddedGraph
 from repro.core.walk import WalkParams, walker_key
 from repro.engine.sampler import HotContext, Sampler, first_order_slots
 
 RW_AXIS = "rw"
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map: jax.shard_map (new) falls back to
-    jax.experimental.shard_map (0.4.x); the replication-check kwarg was
-    renamed check_rep -> check_vma along the way, so gate on the signature."""
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = {}
-    params = inspect.signature(sm).parameters
-    for flag in ("check_vma", "check_rep"):
-        if flag in params:
-            kwargs[flag] = False
-            break
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 @functools.partial(
@@ -103,7 +85,8 @@ class ShardedGraph:
     """Host-built container of device-ready arrays for the sharded engine.
 
     Row-sharded over ``rw``: adj, wgt, alias_p, alias_i, deg.
-    Replicated: hot arrays + per-hot-vertex scalars.
+    Replicated: hot arrays + per-hot-vertex scalars. :meth:`place` puts
+    them on a mesh in that layout.
     """
     n: int            # padded vertex count (multiple of num_shards)
     n_orig: int
@@ -131,6 +114,19 @@ class ShardedGraph:
     def hot_pack(self) -> tuple:
         return (self.hot_ids, self.hot_adj, self.hot_wgt, self.hot_alias_p,
                 self.hot_alias_i, self.hot_deg, self.hot_wmin, self.hot_wmax)
+
+    def place(self, mesh: Mesh) -> "ShardedGraph":
+        """The same graph laid out on ``mesh`` as the walk program reads
+        it, so no call reshards it and no device holds all the rows."""
+        rows = NamedSharding(mesh, P(RW_AXIS))
+        rep = NamedSharding(mesh, P())
+        row_fields = ("adj", "wgt", "alias_p", "alias_i", "deg")
+        return dataclasses.replace(self, **{
+            f: jax.device_put(getattr(self, f),
+                              rows if f in row_fields else rep)
+            for f in row_fields + ("hot_ids", "hot_adj", "hot_wgt",
+                                   "hot_alias_p", "hot_alias_i", "hot_deg",
+                                   "hot_wmin", "hot_wmax")})
 
     @staticmethod
     def from_csr(g, num_shards: int, cap: Optional[int] = None,
@@ -215,19 +211,14 @@ class ShardedGraph:
             hot_wmax = np.full(1, wmax, np.float32)
         hot_alias_p, hot_alias_i = build_alias_rows(hot_wgt)
 
+        # host arrays: place() uploads each shard's rows to its own device
         return ShardedGraph(
             n=n_pad, n_orig=n, num_shards=num_shards, cap=cap,
-            hot_cap=hot_cap,
-            adj=jnp.asarray(adj), wgt=jnp.asarray(wgt),
-            alias_p=jnp.asarray(alias_p), alias_i=jnp.asarray(alias_i),
-            deg=jnp.asarray(deg_pad),
-            hot_ids=jnp.asarray(hot_ids), hot_adj=jnp.asarray(hot_adj),
-            hot_wgt=jnp.asarray(hot_wgt),
-            hot_alias_p=jnp.asarray(hot_alias_p),
-            hot_alias_i=jnp.asarray(hot_alias_i),
-            hot_deg=jnp.asarray(hot_deg),
-            hot_wmin=jnp.asarray(hot_wmin),
-            hot_wmax=jnp.asarray(hot_wmax))
+            hot_cap=hot_cap, adj=adj, wgt=wgt, alias_p=alias_p,
+            alias_i=alias_i, deg=deg_pad, hot_ids=hot_ids, hot_adj=hot_adj,
+            hot_wgt=hot_wgt, hot_alias_p=hot_alias_p,
+            hot_alias_i=hot_alias_i, hot_deg=hot_deg, hot_wmin=hot_wmin,
+            hot_wmax=hot_wmax)
 
     @staticmethod
     def build(pg: PaddedGraph, num_shards: int) -> "ShardedGraph":
@@ -560,9 +551,9 @@ def make_distributed_walk(g: ShardedGraph, mesh: Mesh, params: WalkParams,
 
     # length 1 has no exchanging supersteps — nothing to pipeline
     body_fn = walk_body_pipelined if pipeline and length >= 2 else walk_body
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body_fn, mesh=mesh,
         in_specs=(pspec_rows, pspec_rows, pspec_rows, pspec_rows, pspec_rows,
                   rep, pspec_rows, pspec_rows, rep),
-        out_specs=(pspec_rows, rep))
+        out_specs=(pspec_rows, rep), check_vma=False)
     return jax.jit(shard_fn)

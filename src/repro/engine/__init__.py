@@ -9,7 +9,7 @@
         train_on(r.walks)
 
 Backends: ``reference`` (single-device jnp), ``sharded`` (shard_map over the
-device mesh), ``fused`` (Pallas 2nd-order step kernel; interpret off-TPU).
+device mesh), ``fused`` (Pallas 2nd-order step kernel; interpret mode on CPU).
 All three share one sampling implementation (``repro.engine.sampler``) and
 produce bit-identical walks from the same plan + seed (tested).
 

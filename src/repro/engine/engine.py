@@ -30,7 +30,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core.graph import PaddedGraph
-from repro.core.walk import run_fused_persistent, run_reference
+from repro.core.walk import run_reference
 from repro.core.walk_distributed import (ShardedGraph, make_distributed_walk)
 from repro.engine.plan import WalkPlan, WalkResult, WalkStats
 from repro.engine.update import UpdateReport, patch_padded, patch_sharded
@@ -61,8 +61,6 @@ class WalkEngine:
         self.capacity = capacity
         self.store = store              # GraphStore (update() source of truth)
         self._sampler = plan.sampler()
-        self._no_hot = pg is not None and \
-            int(np.asarray(pg.hot_pos).max(initial=-1)) < 0
         self._delta_edges = 0           # cumulative churn via update()
         self._last_invalidated_fraction = 0.0
 
@@ -112,6 +110,8 @@ class WalkEngine:
             # CSRGraph: pack shard by shard, skipping the dense PaddedGraph
             sg = ShardedGraph.from_csr(graph, num_shards, cap=plan.cap,
                                        hot_cap=plan.hot_cap)
+        if not isinstance(sg.adj, jax.ShapeDtypeStruct):
+            sg = sg.place(rw)
         # capacity default = one full walker block per destination: zero
         # drops, any skew. FN-Multi rounds are the lever for lowering it.
         # Pipelined mode exchanges per *cohort* (half blocks), so the
@@ -151,15 +151,6 @@ class WalkEngine:
         return self.sg is not None and isinstance(self.sg.adj,
                                                   jax.ShapeDtypeStruct)
 
-    def _fused_persistent(self) -> bool:
-        """Pipelined fused backend: the multi-superstep Pallas kernel that
-        carries prev rows in VMEM is used when the layout lets it — exact
-        sampling and FN-Base (no hot set; walks of length >= 2). Otherwise
-        the per-step kernel path runs (bit-identical either way)."""
-        return (self.plan.backend == "fused" and self.plan.pipeline
-                and self._sampler.mode == "exact" and self.plan.length >= 2
-                and self._no_hot)
-
     def _sharded_args(self, starts, walker_ids, key):
         g = self.sg
         return (g.adj, g.wgt, g.alias_p, g.alias_i, g.deg, g.hot_pack(),
@@ -182,13 +173,8 @@ class WalkEngine:
             starts = jnp.asarray(starts, jnp.int32)
             walker_ids = starts if walker_ids is None else \
                 jnp.asarray(walker_ids, jnp.int32)
-            if self._fused_persistent():
-                walks = run_fused_persistent(self.pg, starts, walker_ids,
-                                             key, self._sampler,
-                                             self.plan.length)
-            else:
-                walks = run_reference(self.pg, starts, walker_ids, key,
-                                      self._sampler, self.plan.length)
+            walks = run_reference(self.pg, starts, walker_ids, key,
+                                  self._sampler, self.plan.length)
             return walks, None, None, self._update_meta()
 
         if self._abstract():
@@ -320,15 +306,13 @@ class WalkEngine:
         if self.plan.backend in ("reference", "fused"):
             self.pg, relayout, hot_rows = patch_padded(
                 self.pg, g, aff, self.plan.cap, self.plan.hot_cap)
-            if relayout:
-                self._no_hot = \
-                    int(np.asarray(self.pg.hot_pos).max(initial=-1)) < 0
             device_shards = patch.num_shards
             invalidated = device_shards if relayout \
                 else int(len(patch.affected_shards))
         else:
             self.sg, relayout, inv_shards, hot_rows = patch_sharded(
                 self.sg, g, aff, self.plan.cap, self.plan.hot_cap)
+            self.sg = self.sg.place(self.mesh)
             if relayout:
                 # shapes may have changed (cap / hot set size) -> fresh fn;
                 # capacity stays frozen so the exchange shapes are stable

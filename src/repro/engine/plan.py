@@ -47,9 +47,7 @@ class WalkPlan:
     pipeline: bool = False            # async superstep pipeline (DESIGN §12):
                                       # sharded -> double-buffered cohort
                                       # exchange overlapped with compute;
-                                      # fused -> VMEM-persistent multi-step
-                                      # kernel (exact + FN-Base layout, else
-                                      # per-step kernel); reference -> no-op.
+                                      # reference / fused -> no-op.
                                       # Walks are bit-identical either way.
 
     def __post_init__(self):

@@ -5,7 +5,7 @@ Every walk backend draws the next step through this one implementation:
 * ``repro.core.walk``             — single-device reference engine (vmap),
   which is also the **fused** backend: ``Sampler(fused=True)`` swaps the
   exact-slot computation for the Pallas kernel ``kernels.node2vec_step``
-  (interpret mode off-TPU) with bit-identical results.
+  (interpret mode on the CPU backend) with bit-identical results.
 * ``repro.core.walk_distributed`` — shard_map engine; candidate rows arrive
   via the NEIG all_to_all instead of a local gather, but the sampling math is
   this module, not a copy.
@@ -17,13 +17,13 @@ given the per-(walker, step) key ``k = fold_in(fold_in(seed, walker), step)``:
 
     k_exact, k_approx = split(k)
     r          = uniform(k_exact)                     # ONE uniform per walker
-    slot_exact = count((cumsum(alpha * w) <= r * total) & valid)  # inv. CDF
+    slot_exact = count((prefix_sum(alpha * w) <= r * total) & valid)  # inv. CDF
     slot_alias = alias_sample(k_approx, ...)          # O(1) fast path
 
-The count convention (count of cumsum entries <= target over valid lanes)
-matches the Pallas kernel bit for bit; trailing pad lanes carry zero
-probability so the draw is independent of the padded row width — FN-Base and
-FN-Cache layouts, and all three backends, produce identical walks.
+The Pallas kernel calls the same :func:`draw_slots`, so it matches bit for
+bit; ``prefix_sum`` and ``total`` (the sum at the last live lane) do not
+depend on the padded row width — FN-Base and FN-Cache layouts, and all three
+backends, produce identical walks.
 """
 from __future__ import annotations
 
@@ -47,6 +47,44 @@ def split_keys(keys: jax.Array):
     return k_exact, k_approx
 
 
+def _roll_lanes(x: jnp.ndarray, k: int) -> jnp.ndarray:
+    return jnp.roll(x, k, axis=-1)
+
+
+def prefix_sum(x: jnp.ndarray, roll=_roll_lanes) -> jnp.ndarray:
+    """Inclusive prefix sum over the last axis of a 2-D array, as
+    log2(width) shift-and-add rounds (Hillis-Steele).
+
+    Every element's sum is a fixed tree of f32 adds, so the XLA path
+    (``roll`` = ``jnp.roll``) and the Pallas kernel (``pltpu.roll``) round
+    identically — ``jnp.cumsum`` leaves the order to each backend. Lane i
+    only ever adds lanes <= i, and rounds past i add exact zeros, so the
+    value at a live lane does not depend on how far the row is padded.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    k = 1
+    while k < x.shape[-1]:
+        x = x + jnp.where(lane >= k, roll(x, k), 0.0)
+        k *= 2
+    return x
+
+
+def draw_slots(probs: jnp.ndarray, valid: jnp.ndarray, rand: jnp.ndarray,
+               roll=_roll_lanes) -> jnp.ndarray:
+    """Inverse-CDF draw over unnormalized ``probs`` [W, D] whose ``valid``
+    lanes are a prefix of each row; ``rand`` [W, 1] in [0, 1). Returns the
+    slot [W, 1] i32: the count of live prefix sums <= rand * total, where
+    total is the prefix sum at the last live lane (0 for an empty row)."""
+    cum = prefix_sum(probs, roll)
+    lane = jax.lax.broadcasted_iota(jnp.int32, probs.shape, 1)
+    live = jnp.sum(valid.astype(jnp.int32), axis=-1, keepdims=True)
+    total = jnp.sum(jnp.where(lane == live - 1, cum, 0.0), axis=-1,
+                    keepdims=True)
+    slot = jnp.sum(((cum <= rand * total) & valid).astype(jnp.int32),
+                   axis=-1, keepdims=True)
+    return jnp.minimum(slot, jnp.maximum(live - 1, 0))
+
+
 def exact_slots(cand_ids: jnp.ndarray, cand_w: jnp.ndarray, u: jnp.ndarray,
                 prev_rows: jnp.ndarray, rand: jnp.ndarray, p: float,
                 q: float) -> jnp.ndarray:
@@ -59,11 +97,7 @@ def exact_slots(cand_ids: jnp.ndarray, cand_w: jnp.ndarray, u: jnp.ndarray,
     probs = jax.vmap(
         lambda ci, cw, uu, pr: unnormalized_probs(ci, cw, uu, pr, p, q))(
             cand_ids, cand_w, u, prev_rows)
-    cum = jnp.cumsum(probs, axis=-1)
-    target = rand[:, None] * cum[:, -1:]
-    valid = cand_ids != PAD_ID
-    slot = jnp.sum(((cum <= target) & valid).astype(jnp.int32), axis=-1)
-    return jnp.minimum(slot, cand_ids.shape[-1] - 1)
+    return draw_slots(probs, cand_ids != PAD_ID, rand[:, None])[:, 0]
 
 
 def first_order_slots(keys: jax.Array, alias_p: jnp.ndarray,
@@ -114,7 +148,7 @@ class Sampler:
 
     Frozen + hashable so it can ride through ``jax.jit`` as a static
     argument. ``fused=True`` computes the exact slot with the Pallas kernel
-    (``kernels.ops.node2vec_step_op``, interpret mode off-TPU); the kernel
+    (``kernels.ops.node2vec_step_op``, interpret mode on CPU); the kernel
     implements :func:`exact_slots` verbatim, so results are bit-identical.
     """
     p: float = 1.0

@@ -2,26 +2,29 @@
 
 One kernel fuses the per-walker hot loop of the walk engine:
 
-    membership  x in N(u)        (streamed equality reduction over N(u))
+    membership  x in N(u)        (equality against every rotation of N(u))
     alpha_pq    {1/p, 1, 1/q}    (select)
     probs       alpha * w        (VPU)
-    sampling    inverse-CDF      (cumsum + compare-count, one uniform/walker)
+    sampling    inverse-CDF      (shared prefix sum + compare-count)
 
-The unfused jnp path materializes membership, alpha, probs and the cumsum as
-separate HBM tensors ([W, D] each); fusing keeps everything for a walker block
-resident in VMEM — the step becomes memory-bound on exactly one read of the
-candidate/prev rows, which is the roofline floor for this op.
+The unfused jnp path materializes membership, alpha, probs and the prefix sum
+as separate HBM tensors ([W, D] each); fusing keeps everything for a walker
+block resident in VMEM, so the step reads the candidate/prev rows once.
 
-Tiling: grid over walker blocks (BW rows); the candidate row block
-[BW, D] lives in VMEM, and the membership reduction streams N(u) in LANE-wide
-chunks so the peak VMEM working set is [BW, D] + [BW, D, LANE] bools per
-chunk iteration (bounded, independent of DP).
+The draw itself is :func:`repro.engine.sampler.draw_slots` — the same
+function the jnp path calls, with ``pltpu.roll`` as its lane shift — so the
+kernel and the reference agree bit for bit by construction, on any backend.
 
-Layout contract (matches the walk engines):
+Tiling: grid over walker blocks of BW rows; the candidate and prev row blocks
+[BW, D] live in VMEM. Membership rotates the prev block one lane at a time
+(D rotations, each a [BW, D] compare), so the working set stays at a few
+[BW, D] blocks for any D. ``block_rows`` sizes BW to the padded width.
+
+Layout contract (``kernels.ops`` pads to it):
   cand_ids  [W, D]  i32, PAD_ID padded, row-sorted
   cand_w    [W, D]  f32, 0 padded
   u         [W]     i32 (previous vertex)
-  prev_ids  [W, DP] i32, sorted, PAD_ID padded (N(u))
+  prev_ids  [W, D]  i32, PAD_ID padded (N(u)); same width as cand_ids
   rand      [W]     f32 uniform in [0, 1)
 Returns
   slot      [W]     i32 sampled candidate slot (caller maps to id)
@@ -39,8 +42,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.graph import PAD_ID
+from repro.engine.sampler import draw_slots
 
 LANE = 128
+BLOCK_ELEMS = 8192   # BW * D per block: 8 i32 vregs per [BW, D] value
+
+
+def block_rows(width: int) -> int:
+    """Walker rows per block at padded row ``width``: a power of two in
+    [8, 256] holding about ``BLOCK_ELEMS`` elements per [BW, D] value."""
+    bw = 8
+    while bw < 256 and 2 * bw * width <= BLOCK_ELEMS:
+        bw *= 2
+    return bw
 
 
 def _step_kernel(cand_ids_ref, cand_w_ref, u_ref, prev_ref, rand_ref,
@@ -49,29 +63,21 @@ def _step_kernel(cand_ids_ref, cand_w_ref, u_ref, prev_ref, rand_ref,
     w = cand_w_ref[...]               # [BW, D] f32
     u = u_ref[...]                    # [BW, 1] i32
     r = rand_ref[...]                 # [BW, 1] f32
+    d = cand.shape[-1]
 
-    dp = prev_ref.shape[-1]
-    member = jnp.zeros(cand.shape, jnp.bool_)
+    def body(_, carry):
+        hit, prev = carry
+        hit = hit | (cand == prev).astype(jnp.int32)
+        return hit, pltpu.roll(prev, 1, 1)
 
-    def body(k, member):
-        chunk = prev_ref[:, pl.dslice(k * LANE, LANE)]   # [BW, LANE]
-        eq = cand[:, :, None] == chunk[:, None, :]       # [BW, D, LANE]
-        return member | jnp.any(eq, axis=-1)
+    hit, _ = jax.lax.fori_loop(
+        0, d, body, (jnp.zeros(cand.shape, jnp.int32), prev_ref[...]))
 
-    member = jax.lax.fori_loop(0, dp // LANE, body, member)
-
-    is_u = cand == u                              # [BW, D]
     valid = cand != PAD_ID
-    alpha = jnp.where(is_u, p_inv, jnp.where(member, 1.0, q_inv))
+    alpha = jnp.where(cand == u, p_inv, jnp.where(hit > 0, 1.0, q_inv))
     probs = jnp.where(valid, alpha * w, 0.0)      # [BW, D]
-    cum = jnp.cumsum(probs, axis=-1)
-    total = cum[:, -1:]
-    target = r * total
-    # index of first cumsum entry > target == count of entries <= target
-    slot = jnp.sum(((cum <= target) & valid).astype(jnp.int32), axis=-1,
-                   keepdims=True)
-    slot = jnp.minimum(slot, cand.shape[-1] - 1)
-    slot_ref[...] = slot.astype(jnp.int32)
+    slot_ref[...] = draw_slots(probs, valid, r,
+                               roll=lambda x, k: pltpu.roll(x, k, 1))
 
 
 @functools.partial(jax.jit,
@@ -80,129 +86,24 @@ def node2vec_step(cand_ids: jnp.ndarray, cand_w: jnp.ndarray, u: jnp.ndarray,
                   prev_ids: jnp.ndarray, rand: jnp.ndarray, p: float,
                   q: float, block_w: int = 256,
                   interpret: bool = False) -> jnp.ndarray:
-    """Fused step over all walkers. D/DP must be multiples of 128 and W a
-    multiple of block_w (ops.py pads arbitrary shapes to this contract)."""
+    """Fused step over all walkers. D must be a multiple of 128, prev_ids as
+    wide as cand_ids, and W a multiple of block_w (ops.py pads arbitrary
+    shapes to this contract)."""
     wk, d = cand_ids.shape
-    dp = prev_ids.shape[-1]
-    assert d % LANE == 0 and dp % LANE == 0, (d, dp)
+    assert d % LANE == 0 and prev_ids.shape == cand_ids.shape, \
+        (cand_ids.shape, prev_ids.shape)
     assert wk % block_w == 0, (wk, block_w)
-    grid = (wk // block_w,)
     kernel = functools.partial(_step_kernel, p_inv=1.0 / p, q_inv=1.0 / q)
-
+    rows = pl.BlockSpec((block_w, d), lambda i: (i, 0))
+    col = pl.BlockSpec((block_w, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_w, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, dp), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
+        grid=(wk // block_w,),
+        in_specs=[rows, rows, col, rows, col],
+        out_specs=col,
         out_shape=jax.ShapeDtypeStruct((wk, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(cand_ids, cand_w, u.reshape(wk, 1), prev_ids, rand.reshape(wk, 1))
     return out[:, 0]
-
-
-# ---------------------------------------------------------------------------
-# Multi-superstep persistent-walk kernel (WalkPlan.pipeline, fused backend)
-# ---------------------------------------------------------------------------
-#
-# The per-step kernel above re-reads the [BW, DP] prev-row block from HBM on
-# every superstep even though it is exactly the previous step's candidate
-# block, which was already resident in VMEM when that step ran. This kernel
-# runs the *whole* second-order walk for a walker block inside one
-# pallas_call: the prev rows live in a VMEM scratch buffer that is written
-# once per superstep (from the candidate block that is in VMEM anyway) and
-# never round-trips through HBM. Per superstep the only HBM traffic is the
-# candidate-row gather from the graph and one [BW] column of the output.
-#
-# Scope: exact sampling on the FN-Base layout (cap == max degree, empty hot
-# set) — the hot-cache/approx paths keep using the per-step kernel. Step 0
-# (the first-order alias draw) happens on the host; the kernel runs steps
-# 1..length-1 with host-precomputed uniforms (the RNG is a pure function of
-# (walker, step), so walks stay bit-identical to the reference backend).
-#
-# TPU caveat: the candidate gather is a dynamic row gather from the graph
-# block; on real hardware the graph block must fit VMEM (small/medium graphs
-# or a per-shard slice) — this container is interpret-only, where the gather
-# is exact but unprofiled.
-
-
-def _walk_kernel(adj_ref, wgt_ref, deg_ref, u0_ref, v1_ref, rand_ref,
-                 out_ref, prev_scratch, *, p_inv: float, q_inv: float,
-                 length: int):
-    adj = adj_ref[...]                # [n, D] i32 (graph block, VMEM)
-    wgt = wgt_ref[...]                # [n, D] f32
-    deg = deg_ref[...][:, 0]          # [n]    i32
-    # prev rows for step 1 = N(u0): gathered once, then carried in VMEM
-    prev_scratch[...] = jnp.take(adj, u0_ref[...][:, 0], axis=0)
-
-    def body(s, carry):
-        u, v = carry                                  # [BW] each
-        cand = jnp.take(adj, v, axis=0)               # [BW, D]
-        w = jnp.take(wgt, v, axis=0)
-
-        # membership vs the VMEM-carried prev rows, LANE-chunked (same
-        # bounded working set as the per-step kernel)
-        def mem_body(k, member):
-            chunk = prev_scratch[:, pl.dslice(k * LANE, LANE)]
-            eq = cand[:, :, None] == chunk[:, None, :]
-            return member | jnp.any(eq, axis=-1)
-
-        member = jax.lax.fori_loop(0, cand.shape[-1] // LANE, mem_body,
-                                   jnp.zeros(cand.shape, jnp.bool_))
-        is_u = cand == u[:, None]
-        valid = cand != PAD_ID
-        alpha = jnp.where(is_u, p_inv, jnp.where(member, 1.0, q_inv))
-        probs = jnp.where(valid, alpha * w, 0.0)
-        cum = jnp.cumsum(probs, axis=-1)
-        target = rand_ref[:, pl.dslice(s, 1)] * cum[:, -1:]
-        slot = jnp.sum(((cum <= target) & valid).astype(jnp.int32), axis=-1)
-        slot = jnp.minimum(slot, cand.shape[-1] - 1)
-        nxt = jnp.take_along_axis(cand, slot[:, None], axis=1)[:, 0]
-        nxt = jnp.where(jnp.take(deg, v) > 0, nxt, v)  # dead end: stay
-        prev_scratch[...] = cand                       # N(v) for step s+2
-        out_ref[:, pl.dslice(s, 1)] = nxt[:, None]
-        return v, nxt
-
-    jax.lax.fori_loop(0, length - 1, body, (u0_ref[...][:, 0],
-                                            v1_ref[...][:, 0]))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("p", "q", "block_w", "interpret"))
-def node2vec_walk(adj: jnp.ndarray, wgt: jnp.ndarray, deg: jnp.ndarray,
-                  u0: jnp.ndarray, v1: jnp.ndarray, rand: jnp.ndarray,
-                  p: float, q: float, block_w: int = 256,
-                  interpret: bool = False) -> jnp.ndarray:
-    """Persistent fused walk: steps 1..length-1 for all walkers, prev rows
-    carried in VMEM. adj/wgt [n, D] (D a LANE multiple), deg [n], u0/v1 [W]
-    (start vertex / step-0 result), rand [W, length-1] uniforms. Returns
-    [W, length-1] sampled vertices (v_2..v_length)."""
-    n, d = adj.shape
-    wk, steps = rand.shape
-    assert d % LANE == 0, d
-    assert wk % block_w == 0, (wk, block_w)
-    grid = (wk // block_w,)
-    kernel = functools.partial(_walk_kernel, p_inv=1.0 / p, q_inv=1.0 / q,
-                               length=steps + 1)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, d), lambda i: (0, 0)),       # graph: replicated
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, steps), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_w, steps), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((wk, steps), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_w, d), jnp.int32)],
-        interpret=interpret,
-    )(adj, wgt, deg.reshape(n, 1), u0.reshape(wk, 1), v1.reshape(wk, 1),
-      rand)

@@ -1,9 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles the shape contract (pad walker count / widths to tile multiples),
-chooses interpret mode off-TPU (this container is CPU-only; interpret=True
-executes the kernel body faithfully for validation), and exposes drop-in
-replacements for the jnp paths in the walk engine / SGNS trainer.
+runs the kernels in Pallas interpret mode on the CPU backend only (it
+executes the kernel body faithfully for validation; every accelerator
+compiles them), and exposes drop-in replacements for the jnp paths in the
+walk engine / SGNS trainer.
 """
 from __future__ import annotations
 
@@ -17,8 +18,10 @@ from repro.kernels import node2vec_step as _step
 from repro.kernels import sgns as _sgns
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas interpret mode runs the kernel bodies on the CPU backend only;
+    every accelerator compiles them."""
+    return jax.default_backend() == "cpu"
 
 
 def _pad_axis(x, axis: int, mult: int, fill):
@@ -31,54 +34,34 @@ def _pad_axis(x, axis: int, mult: int, fill):
     return jnp.pad(x, pads, constant_values=fill)
 
 
-def node2vec_step_op(cand_ids, cand_w, u, prev_ids, rand, p: float, q: float,
-                     block_w: int = 256, interpret=None) -> jnp.ndarray:
-    """Fused 2nd-order step; pads to the kernel tile contract and unpads."""
-    if interpret is None:
-        interpret = not _on_tpu()
+def node2vec_step_op(cand_ids, cand_w, u, prev_ids, rand, p: float,
+                     q: float) -> jnp.ndarray:
+    """Fused 2nd-order step; pads candidate and prev rows to one lane-multiple
+    width and the walker count to the block multiple, then unpads."""
     w = cand_ids.shape[0]
-    bw = min(block_w, max(8, 1 << (w - 1).bit_length()))
-    cand_ids = _pad_axis(_pad_axis(cand_ids, 1, _step.LANE, PAD_ID), 0, bw,
-                         PAD_ID)
-    cand_w = _pad_axis(_pad_axis(cand_w, 1, _step.LANE, 0.0), 0, bw, 0.0)
-    prev_ids = _pad_axis(_pad_axis(prev_ids, 1, _step.LANE, PAD_ID), 0, bw,
-                         PAD_ID)
-    u = _pad_axis(u, 0, bw, 0)
-    rand = _pad_axis(rand, 0, bw, 0.0)
-    slots = _step.node2vec_step(cand_ids, cand_w, u, prev_ids, rand, p, q,
-                                block_w=min(bw, cand_ids.shape[0]),
-                                interpret=interpret)
+    width = _step.LANE * -(-max(cand_ids.shape[1], prev_ids.shape[1])
+                          // _step.LANE)
+    bw = min(_step.block_rows(width), max(8, 1 << (w - 1).bit_length()))
+
+    def pad(x, fill):
+        if x.ndim == 2:
+            x = jnp.pad(x, ((0, 0), (0, width - x.shape[1])),
+                        constant_values=fill)
+        return _pad_axis(x, 0, bw, fill)
+
+    slots = _step.node2vec_step(
+        pad(cand_ids, PAD_ID), pad(cand_w, 0.0), pad(u, 0),
+        pad(prev_ids, PAD_ID), pad(rand, 0.0), p, q, block_w=bw,
+        interpret=_interpret())
     return slots[:w]
 
 
-def node2vec_walk_op(adj, wgt, deg, u0, v1, rand, p: float, q: float,
-                     block_w: int = 256, interpret=None) -> jnp.ndarray:
-    """Persistent fused walk (prev rows carried in VMEM across supersteps);
-    pads the graph width to the lane multiple and the walker count to the
-    block multiple, then unpads. Returns [W, steps] sampled vertices."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    w = u0.shape[0]
-    bw = min(block_w, max(8, 1 << (w - 1).bit_length()))
-    adj = _pad_axis(adj, 1, _step.LANE, PAD_ID)
-    wgt = _pad_axis(wgt, 1, _step.LANE, 0.0)
-    u0 = _pad_axis(u0, 0, bw, 0)
-    v1 = _pad_axis(v1, 0, bw, 0)
-    rand = _pad_axis(rand, 0, bw, 0.0)
-    out = _step.node2vec_walk(adj, wgt, deg, u0, v1, rand, p, q,
-                              block_w=min(bw, u0.shape[0]),
-                              interpret=interpret)
-    return out[:w]
-
-
 def flash_attention_op(q, k, v, window: int = 0, causal: bool = True,
-                       block: int = 128, interpret=None):
+                       block: int = 128):
     """Flash attention over model-layout tensors: q [B,S,H,dh],
     k/v [B,S,KV,dh] (GQA expanded here). Pads S to the block multiple and dh
     to the lane width."""
     from repro.kernels import flash_attention as _fa
-    if interpret is None:
-        interpret = not _on_tpu()
     b, s, h, dh = q.shape
     kv = k.shape[2]
     if kv != h:
@@ -94,15 +77,13 @@ def flash_attention_op(q, k, v, window: int = 0, causal: bool = True,
     qq, kk, vv = map(to_bh, (q, k, v))
     out = _fa.flash_attention(qq, kk, vv, block=min(bq, qq.shape[1]),
                               window=window, causal=causal,
-                              interpret=interpret, sm_scale=dh ** -0.5)
+                              interpret=_interpret(), sm_scale=dh ** -0.5)
     out = out[:, :s, :dh].reshape(b, h, s, dh)
     return jnp.swapaxes(out, 1, 2)
 
 
-def sgns_fused_op(ci, po, no, valid, block_b: int = 512, interpret=None):
+def sgns_fused_op(ci, po, no, valid, block_b: int = 512):
     """Fused SGNS loss+grads; returns (loss_sum, g_ci, g_po, g_no)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b, d = ci.shape
     bb = min(block_b, max(8, 1 << (b - 1).bit_length()))
     ci_p = _pad_axis(_pad_axis(ci, 1, _sgns.LANE, 0.0), 0, bb, 0.0)
@@ -111,5 +92,5 @@ def sgns_fused_op(ci, po, no, valid, block_b: int = 512, interpret=None):
     valid_p = _pad_axis(valid, 0, bb, 0.0)
     loss, g_ci, g_po, g_no = _sgns.sgns_fused(
         ci_p, po_p, no_p, valid_p, block_b=min(bb, ci_p.shape[0]),
-        interpret=interpret)
+        interpret=_interpret())
     return loss, g_ci[:b, :d], g_po[:b, :d], g_no[:b, :, :d]

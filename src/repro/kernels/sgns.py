@@ -14,7 +14,8 @@ the arithmetic-intensity floor for this op. Embedding dim D is the lane axis
 (multiple of 128); negatives K is unrolled (small, e.g. 5-8).
 
 Shapes: ci, po [B, D] f32; no [B, K, D] f32; valid [B] f32 mask.
-Out: loss_sum [1, 1] (masked sum), g_ci, g_po [B, D], g_no [B, K, D].
+Out: loss_sum (masked sum, added from per-block partials), g_ci, g_po [B, D],
+g_no [B, K, D].
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 
@@ -33,33 +35,27 @@ def _sigmoid(x):
 
 def _sgns_kernel(ci_ref, po_ref, no_ref, valid_ref, loss_ref, gci_ref,
                  gpo_ref, gno_ref):
-    i = pl.program_id(0)
     ci = ci_ref[...]              # [B, D]
     po = po_ref[...]              # [B, D]
-    no = no_ref[...]              # [B, K, D]
     valid = valid_ref[...]        # [B, 1]
 
-    pos_score = jnp.sum(ci * po, axis=-1, keepdims=True)       # [B, 1]
-    s_p = _sigmoid(pos_score)
-    neg_score = jnp.sum(no * ci[:, None, :], axis=-1)          # [B, K]
-    s_n = _sigmoid(neg_score)
-
     # loss = -log s_p - sum log(1 - s_n) = softplus(-x_p) + sum softplus(x_n)
-    loss = (jnp.logaddexp(0.0, -pos_score[:, 0]) +
-            jnp.sum(jnp.logaddexp(0.0, neg_score), axis=-1))   # [B]
-    masked = loss * valid[:, 0]
-
-    @pl.when(i == 0)
-    def _init():
-        loss_ref[...] = jnp.zeros_like(loss_ref)
-
-    loss_ref[0, 0] += jnp.sum(masked)
-
-    coeff_p = (s_p - 1.0) * valid                              # [B, 1]
-    coeff_n = s_n * valid                                      # [B, K]
+    pos_score = jnp.sum(ci * po, axis=-1, keepdims=True)       # [B, 1]
+    loss = jnp.logaddexp(0.0, -pos_score)
+    coeff_p = (_sigmoid(pos_score) - 1.0) * valid              # [B, 1]
     gpo_ref[...] = coeff_p * ci
-    gno_ref[...] = coeff_n[:, :, None] * ci[:, None, :]
-    gci_ref[...] = coeff_p * po + jnp.sum(coeff_n[:, :, None] * no, axis=1)
+    g_ci = coeff_p * po
+    for k in range(no_ref.shape[1]):                           # K unrolled
+        no_k = no_ref[:, k, :]                                 # [B, D]
+        neg_score = jnp.sum(ci * no_k, axis=-1, keepdims=True)
+        loss = loss + jnp.logaddexp(0.0, neg_score)
+        coeff_n = _sigmoid(neg_score) * valid
+        gno_ref[:, k, :] = coeff_n * ci
+        g_ci = g_ci + coeff_n * no_k
+    gci_ref[...] = g_ci
+    # this block's masked loss sum, broadcast over its own (8, 128) tile;
+    # the wrapper adds the per-block partials
+    loss_ref[...] = jnp.broadcast_to(jnp.sum(loss * valid), loss_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -72,32 +68,26 @@ def sgns_fused(ci: jnp.ndarray, po: jnp.ndarray, no: jnp.ndarray,
     k = no.shape[1]
     assert d % LANE == 0 and b % block_b == 0, (b, d)
     grid = (b // block_b,)
-
-    out = pl.pallas_call(
+    rows = pl.BlockSpec((block_b, d), lambda i: (i, 0))
+    negs = pl.BlockSpec((block_b, k, d), lambda i: (i, 0, 0))
+    partial, g_ci, g_po, g_no = pl.pallas_call(
         _sgns_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, k, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, k, d), lambda i: (i, 0, 0)),
-        ],
+        in_specs=[rows, rows, negs,
+                  pl.BlockSpec((block_b, 1), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((1, 8, LANE), lambda i: (i, 0, 0)),
+                   rows, rows, negs],
         out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 8, LANE), jnp.float32),
             jax.ShapeDtypeStruct((b, d), jnp.float32),
             jax.ShapeDtypeStruct((b, d), jnp.float32),
             jax.ShapeDtypeStruct((b, k, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(ci, po, no, valid.reshape(b, 1))
-    loss_sum, g_ci, g_po, g_no = out
-    return loss_sum[0, 0], g_ci, g_po, g_no
+    return jnp.sum(partial[:, 0, 0]), g_ci, g_po, g_no
 
 
 def sgns_row_grads(ci, po, no, valid, backend: str = "jnp"):

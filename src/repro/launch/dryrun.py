@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.optim.optimizers import adamw, apply_updates
@@ -179,10 +179,10 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     if mesh_shape is not None:
         d, m = mesh_shape
         if multi_pod:
-            mesh = jax.make_mesh((2, d, m), ("pod", "data", "model"))
+            mesh = make_mesh((2, d, m), ("pod", "data", "model"))
             mesh_name = f"pod2x{d}x{m}"
         else:
-            mesh = jax.make_mesh((d, m), ("data", "model"))
+            mesh = make_mesh((d, m), ("data", "model"))
             mesh_name = f"pod{d}x{m}"
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

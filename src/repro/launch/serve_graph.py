@@ -20,6 +20,7 @@ import numpy as np
 from repro.core.node2vec import Node2VecConfig
 from repro.data import open_graph
 from repro.engine import WalkPlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import EmbeddingService, synthetic_trace
 
 
@@ -109,6 +110,7 @@ def main() -> None:
     ap.add_argument("--margin-ms", type=float, default=1.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.graph is None:
         args.graph = ("skew:s=4,k=9,deg=20,seed=3,relabel=degree"

@@ -13,9 +13,10 @@ Two modes, selectable via ``--task``:
   production sharding rules, checkpoint/restart, and (optionally) int8
   error-feedback gradient compression across data-parallel replicas.
 
-This launcher is sized to run REAL steps on whatever devices exist (CPU here,
-TPU pod in production); the dry-run path (launch/dryrun.py) covers the
-production mesh shapes.
+This launcher runs real steps on whatever devices exist (the CPU backend in
+tests, one TPU chip or a TPU host); the dry-run path (launch/dryrun.py)
+covers the production mesh shapes. It keeps JAX's compile cache in
+``JAX_COMPILATION_CACHE_DIR`` or else ``<checkout>/.jax_cache``.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --task node2vec --k 10 --rounds 2
@@ -38,6 +39,7 @@ from repro.core.node2vec import Node2VecConfig, train_embeddings
 from repro.data import open_graph
 from repro.data.corpus import walks_to_lm_tokens
 from repro.engine import WalkEngine, WalkPlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_rw_mesh
 from repro.models import model as M
 from repro.optim.optimizers import adamw, apply_updates
@@ -182,7 +184,7 @@ def main():
     ap.add_argument("--sgns-backend", choices=["jnp", "fused"],
                     default="jnp",
                     help="stage-2 gradient backend: jnp autodiff or the "
-                         "fused Pallas SGNS kernel (interpret off-TPU)")
+                         "fused Pallas SGNS kernel (interpret mode on CPU)")
     ap.add_argument("--concat", action="store_true",
                     help="generate-then-train baseline instead of the "
                          "streamed on-device trainer")
@@ -200,6 +202,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.task == "node2vec":
         run_node2vec(args)
     else:

@@ -46,10 +46,8 @@ _BYTES = {"f64": 8, "s64": 8, "u64": 8, "f32": 4, "s32": 4, "u32": 4,
 
 
 def cost_dict(ca) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` output: some jax versions
-    return a dict, others (e.g. 0.4.37) a one-element list of dicts."""
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
+    """``compiled.cost_analysis()`` as a plain dict (empty where the backend
+    reports nothing)."""
     return dict(ca or {})
 
 

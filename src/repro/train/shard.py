@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.walk_distributed import RW_AXIS, _shard_map
+from repro.core.walk_distributed import RW_AXIS
 from repro.kernels.sgns import sgns_row_grads
 from repro.optim.optimizers import AdamState
 from repro.train.pairs import device_negatives
@@ -72,18 +72,20 @@ def table_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(RW_AXIS))
 
 
-def shard_params(params, vocab: int, mesh: Mesh):
-    """Pad the [V, D] tables to the mesh multiple and place them
-    range-sharded. Identical values to the single-device tables on rows
-    [:V]; the pad rows are zero."""
-    vp = table_rows(vocab, mesh_shards(mesh))
-    sh = table_sharding(mesh)
+def init_sharded_params(cfg, key, mesh: Mesh):
+    """``init_params(cfg, key)`` padded to the mesh multiple, generated
+    straight into the range-sharded layout: no device ever holds a whole
+    table. Threefry is partitionable, so the values do not depend on the
+    shard count."""
+    from repro.core.skipgram import init_params
+    vp = table_rows(cfg.vocab, mesh_shards(mesh))
 
-    def place(t):
-        t = jnp.pad(t, ((0, vp - t.shape[0]), (0, 0)))
-        return jax.device_put(t, sh)
+    def init(key):
+        return jax.tree.map(
+            lambda t: jnp.pad(t, ((0, vp - t.shape[0]), (0, 0))),
+            init_params(cfg, key))
 
-    return jax.tree.map(place, params)
+    return jax.jit(init, out_shardings=table_sharding(mesh))(key)
 
 
 def shard_opt_state(params_sharded, mesh: Mesh) -> AdamState:
@@ -94,7 +96,7 @@ def shard_opt_state(params_sharded, mesh: Mesh) -> AdamState:
     sh = table_sharding(mesh)
 
     def zeros(p):
-        return jax.device_put(jnp.zeros(p.shape, p.dtype), sh)
+        return jnp.zeros(p.shape, p.dtype, device=sh)
 
     count = jax.device_put(jnp.zeros((), jnp.int32),
                            NamedSharding(mesh, P()))
@@ -197,9 +199,9 @@ def train_epoch_sharded(params, opt_state, c, x, valid, perm2d, prob, alias,
         return params_loc, opt_loc, losses
 
     state_spec = AdamState(P(), P(RW_AXIS), P(RW_AXIS))
-    sharded = _shard_map(
-        epoch, mesh,
+    sharded = jax.shard_map(
+        epoch, mesh=mesh,
         in_specs=(P(RW_AXIS), state_spec,
                   P(), P(), P(), P(), P(), P(), P()),
-        out_specs=(P(RW_AXIS), state_spec, P()))
+        out_specs=(P(RW_AXIS), state_spec, P()), check_vma=False)
     return sharded(params, opt_state, c, x, valid, perm2d, prob, alias, key)

@@ -41,8 +41,9 @@ from repro.core.skipgram import (SGNSConfig, init_params, normalize_embeddings,
                                  sgns_grads)
 from repro.optim.optimizers import adam, adam_rows, apply_updates
 from repro.train.pairs import device_negatives, device_pairs, num_pairs
-from repro.train.shard import (mesh_shards, pow2_bucket, shard_opt_state,
-                               shard_params, train_epoch_sharded)
+from repro.train.shard import (init_sharded_params, mesh_shards,
+                               pow2_bucket, shard_opt_state,
+                               train_epoch_sharded)
 from repro.train.stats import TrainRecorder, TrainStats
 
 
@@ -125,7 +126,6 @@ class StreamingSGNSTrainer:
         self.record_loss = record_loss
         self.shard_tables = bool(shard_tables)
         scfg = SGNSConfig(vocab=vocab, dim=dim, negatives=negatives)
-        self.params = init_params(scfg, jax.random.PRNGKey(seed))
         if self.shard_tables:
             # mesh-partitioned tables + lazy row-Adam (repro.train.shard):
             # same init values, padded to the shard multiple, range-sharded
@@ -134,7 +134,8 @@ class StreamingSGNSTrainer:
             self.mesh = mesh if isinstance(mesh, Mesh) and \
                 tuple(mesh.axis_names) == ("rw",) else make_table_mesh(mesh)
             self.shards = mesh_shards(self.mesh)
-            self.params = shard_params(self.params, vocab, self.mesh)
+            self.params = init_sharded_params(scfg, jax.random.PRNGKey(seed),
+                                              self.mesh)
             self._opt = adam_rows(lr)
             self.opt_state = shard_opt_state(self.params, self.mesh)
             self._u_in = pow2_bucket(batch_size)
@@ -142,6 +143,7 @@ class StreamingSGNSTrainer:
         else:
             self.mesh = None
             self.shards = 1
+            self.params = init_params(scfg, jax.random.PRNGKey(seed))
             self._opt = adam(lr)
             self.opt_state = self._opt.init(self.params)
         self._counts = np.zeros(vocab, np.float64)

@@ -43,7 +43,8 @@ SMALL_MESH_SCRIPT = textwrap.dedent("""
     from repro import configs
     from repro.launch.dryrun import lower_cell
     from repro.roofline.analysis import cost_dict
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg0 = configs.get_config("{arch}")
     pattern = len(cfg0.superblock())
     cfg = dataclasses.replace(cfg0, num_layers=pattern,

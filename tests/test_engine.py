@@ -109,15 +109,14 @@ def test_stats_surface_drops_and_strict_flag():
 
 @pytest.mark.parametrize("wk,length", [(32, 8), (7, 5), (5, 2)])
 def test_fused_persistent_pipeline_parity(small_graph, wk, length):
-    """WalkPlan.pipeline on the fused backend routes exact FN-Base walks to
-    the multi-superstep Pallas kernel (prev rows carried in VMEM) — walks
-    must stay bit-identical to the reference backend, including odd walker
-    counts and the minimal length-2 walk."""
+    """WalkPlan.pipeline on the fused backend (exact FN-Base walks through
+    the per-step Pallas kernel) — walks must stay bit-identical to the
+    reference backend, including odd walker counts and the minimal length-2
+    walk."""
     kw = dict(p=0.5, q=2.0, length=length)       # cap=None -> FN-Base
     ref = WalkEngine.build(small_graph, WalkPlan(backend="reference", **kw))
     fus = WalkEngine.build(small_graph,
                            WalkPlan(backend="fused", pipeline=True, **kw))
-    assert fus._fused_persistent()               # the kernel path is live
     starts = ((np.arange(wk) * 3) % small_graph.n).astype(np.int32)
     wid = np.arange(wk, dtype=np.int32)
     r = ref.run(starts=starts, seed=11, walker_ids=wid)
@@ -127,14 +126,12 @@ def test_fused_persistent_pipeline_parity(small_graph, wk, length):
 
 @pytest.mark.parametrize("mode", ["approx", "approx_always"])
 def test_fused_pipeline_fallback_parity(skewed_graph, mode):
-    """Outside the persistent kernel's scope (hot-cache layout / approx
-    sampling) the pipeline flag falls back to the per-step kernel — still
-    bit-identical to the reference."""
+    """Hot-cache layout and approx sampling with the pipeline flag on the
+    fused backend — still bit-identical to the reference."""
     kw = dict(p=0.5, q=2.0, length=6, mode=mode, approx_eps=5e-2, cap=24)
     ref = WalkEngine.build(skewed_graph, WalkPlan(backend="reference", **kw))
     fus = WalkEngine.build(skewed_graph,
                            WalkPlan(backend="fused", pipeline=True, **kw))
-    assert not fus._fused_persistent()
     assert np.array_equal(ref.run(seed=3).walks, fus.run(seed=3).walks)
 
 

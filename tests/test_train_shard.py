@@ -16,12 +16,13 @@ import pytest
 
 from repro.core.alias import build_alias
 from repro.core.skipgram import SGNSConfig, init_params
-from repro.core.walk_distributed import RW_AXIS, _shard_map
+from repro.core.walk_distributed import RW_AXIS
 from repro.data.corpus import NegativeSampler
 from repro.launch.mesh import make_table_mesh
 from repro.optim.optimizers import adam_rows
-from repro.train import (StreamingSGNSTrainer, pow2_bucket, shard_opt_state,
-                         shard_params, table_rows, train_epoch_sharded)
+from repro.train import (StreamingSGNSTrainer, init_sharded_params,
+                         pow2_bucket, shard_opt_state, table_rows,
+                         train_epoch_sharded)
 from repro.train.pairs import device_negatives
 from jax.sharding import PartitionSpec as P
 
@@ -81,10 +82,10 @@ def test_sharded_epoch_matches_numpy_reference():
     key = jax.random.PRNGKey(3)
     mesh = make_table_mesh(max_shards=1)
     opt = adam_rows(0.025)
-    params = init_params(SGNSConfig(vocab=V, dim=D, negatives=K),
-                         jax.random.PRNGKey(0))
-    ref = {k: np.asarray(v, np.float64) for k, v in params.items()}
-    params = shard_params(params, V, mesh)
+    cfg = SGNSConfig(vocab=V, dim=D, negatives=K)
+    ref = {k: np.asarray(v, np.float64)
+           for k, v in init_params(cfg, jax.random.PRNGKey(0)).items()}
+    params = init_sharded_params(cfg, jax.random.PRNGKey(0), mesh)
     state = shard_opt_state(params, mesh)
     u_in, u_out = pow2_bucket(B), pow2_bucket(B * (1 + K))
     p2, s2, losses = train_epoch_sharded(
@@ -209,9 +210,10 @@ def test_sharded_negative_draws_replay_single_device_stream():
     key = jax.random.PRNGKey(9)
     mesh = make_table_mesh(max_shards=1)
     direct = device_negatives(key, prob, alias, (32, 5))
-    sharded = _shard_map(
-        lambda p, a, k: device_negatives(k, p, a, (32, 5)), mesh,
-        in_specs=(P(), P(), P()), out_specs=P())(prob, alias, key)
+    sharded = jax.shard_map(
+        lambda p, a, k: device_negatives(k, p, a, (32, 5)), mesh=mesh,
+        in_specs=(P(), P(), P()), out_specs=P(),
+        check_vma=False)(prob, alias, key)
     np.testing.assert_array_equal(np.asarray(direct), np.asarray(sharded))
 
 
@@ -256,11 +258,11 @@ TWO_DEV_STEP_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import numpy as np, jax, jax.numpy as jnp
     from repro.core.alias import build_alias
-    from repro.core.skipgram import SGNSConfig, init_params
+    from repro.core.skipgram import SGNSConfig
     from repro.launch.mesh import make_table_mesh
     from repro.optim.optimizers import adam_rows
-    from repro.train import (pow2_bucket, shard_opt_state, shard_params,
-                             train_epoch_sharded)
+    from repro.train import (init_sharded_params, pow2_bucket,
+                             shard_opt_state, train_epoch_sharded)
 
     V, D, B, K, steps = 101, 8, 32, 3, 3
     rng = np.random.default_rng(2)
@@ -278,9 +280,9 @@ TWO_DEV_STEP_SCRIPT = textwrap.dedent("""
     out = {{}}
     for s in (1, 2):
         mesh = make_table_mesh(max_shards=s)
-        params = shard_params(
-            init_params(SGNSConfig(vocab=V, dim=D, negatives=K),
-                        jax.random.PRNGKey(0)), V, mesh)
+        params = init_sharded_params(
+            SGNSConfig(vocab=V, dim=D, negatives=K), jax.random.PRNGKey(0),
+            mesh)
         state = shard_opt_state(params, mesh)
         p2, s2, losses = train_epoch_sharded(
             params, state, c, x, valid, perm2d, prob, alias, key,
