@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent, in the SGNS cells. Moves sgns_pairs_per_s."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t is None or not t.busy_s:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)

@@ -1,0 +1,12 @@
+"""Essential SGNS bytes (``chipbench/core/work.py``: the center, positive
+and negative rows, read once and written once, per step) over device busy
+time times the chip's peak HBM bandwidth, in percent (trainer / kernels).
+Moves sgns_pairs_per_s."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    nbytes = ctx["counts"].get("sgns_bytes")
+    if t is None or not t.busy_s or not nbytes or ctx["peaks"] is None:
+        return None
+    return 100.0 * nbytes / (t.busy_s * ctx["peaks"]["hbm_bytes_per_s"])
