@@ -15,10 +15,6 @@ Smoke mode (``--smoke [out.json]``) merges **ratio** metrics into the
                                       through the host corpus path
                                       (interleaved runs; machine load
                                       cancels; < 1 means streaming wins).
-* ``train_h2d_stream_over_concat``  — host→device bytes of the streamed
-                                      path over the per-batch staging the
-                                      host path uploads. Deterministic
-                                      layout arithmetic — exact.
 * ``train_fused_over_jnp_step_us``  — per-train-step wall ratio of the
                                       fused Pallas SGNS backend over jnp
                                       autodiff (interpret mode on CPU, so
@@ -241,8 +237,7 @@ def run() -> None:
     t_s, t_ch, t_cd, st = _interleaved(g, cfg)
     row("train_stream", t_s * 1e6,
         f"pairs_per_sec={st.pairs / t_s:.0f};"
-        f"tokens_per_sec={st.tokens / t_s:.0f};"
-        f"overlap_efficiency={st.overlap_efficiency:.2f}")
+        f"tokens_per_sec={st.tokens / t_s:.0f}")
     row("train_concat_host", t_ch * 1e6,
         f"stream_speedup={t_ch / t_s:.2f}x")
     row("train_concat_dev", t_cd * 1e6,
@@ -273,7 +268,6 @@ def smoke_metrics(info: dict) -> dict:
         "train_steps": st.steps,
         "train_pairs_per_sec": st.pairs / t_s,
         "train_tokens_per_sec": st.tokens / t_s,
-        "train_overlap_efficiency": st.overlap_efficiency,
     })
     jnp_us = _step_us("jnp", cfg)
     fused_us = _step_us("fused", cfg)
@@ -295,8 +289,6 @@ def smoke_metrics(info: dict) -> dict:
     })
     return {
         "train_stream_over_concat_us": t_s / t_ch,
-        "train_h2d_stream_over_concat":
-            st.h2d_bytes / st.h2d_bytes_concat,
         "train_fused_over_jnp_step_us": fused_us / jnp_us,
         "train_shard_pairs_ratio": ratio,
         "train_shard_bit_identical": res["bit_identical"],

@@ -121,27 +121,35 @@ def _batched_rows(pg: PaddedGraph, v: jnp.ndarray):
 def _simulate(pg: PaddedGraph, starts: jnp.ndarray, walker_ids: jnp.ndarray,
               seed_key: jax.Array, sampler: Sampler, length: int):
     # step 0: 1st-order draw from static edge weights via the alias table
-    k0 = jax.vmap(lambda i: walker_key(seed_key, i, 0))(walker_ids)
-    ids0, _, ap0, ai0, _ = _batched_rows(pg, starts)
-    deg0 = pg.deg[starts]
-    slot0 = first_order_slots(k0, ap0, ai0, deg0)
-    nxt0 = jnp.take_along_axis(ids0, slot0[:, None], axis=1)[:, 0]
-    v1 = jnp.where(deg0 > 0, nxt0, starts)
+    with jax.named_scope("walk.rng"):
+        k0 = jax.vmap(lambda i: walker_key(seed_key, i, 0))(walker_ids)
+    with jax.named_scope("walk.rows"):
+        ids0, _, ap0, ai0, _ = _batched_rows(pg, starts)
+        deg0 = pg.deg[starts]
+    with jax.named_scope("walk.draw"):
+        slot0 = first_order_slots(k0, ap0, ai0, deg0)
+        nxt0 = jnp.take_along_axis(ids0, slot0[:, None], axis=1)[:, 0]
+        v1 = jnp.where(deg0 > 0, nxt0, starts)
 
     def body(carry, s):
         u, v, prev_ids = carry
-        keys = jax.vmap(lambda i: walker_key(seed_key, i, s))(walker_ids)
-        ids, w, ap, ai, is_hot = _batched_rows(pg, v)
-        hot = None
-        if sampler.mode != "exact":
-            hot = HotContext(
-                is_hot_v=is_hot, is_hot_u=pg.hot_pos[u] >= 0,
-                deg_u=pg.deg[u], deg_v=pg.deg[v],
-                w_min_v=pg.w_min[v], w_max_v=pg.w_max[v],
-                alias_p=ap, alias_i=ai, alias_deg=pg.deg[v])
+        with jax.named_scope("walk.rng"):
+            keys = jax.vmap(lambda i: walker_key(seed_key, i, s))(walker_ids)
+        with jax.named_scope("walk.rows"):
+            ids, w, ap, ai, is_hot = _batched_rows(pg, v)
+            deg_v = pg.deg[v]
+            hot = None
+            if sampler.mode != "exact":
+                hot = HotContext(
+                    is_hot_v=is_hot, is_hot_u=pg.hot_pos[u] >= 0,
+                    deg_u=pg.deg[u], deg_v=deg_v,
+                    w_min_v=pg.w_min[v], w_max_v=pg.w_max[v],
+                    alias_p=ap, alias_i=ai, alias_deg=deg_v)
         choice = sampler.choose(keys, ids, w, u, prev_ids, hot)
-        nxt = jnp.take_along_axis(ids, choice.slot()[:, None], axis=1)[:, 0]
-        nxt = jnp.where(pg.deg[v] > 0, nxt, v)  # dead end: stay
+        with jax.named_scope("walk.draw"):
+            nxt = jnp.take_along_axis(ids, choice.slot()[:, None],
+                                      axis=1)[:, 0]
+            nxt = jnp.where(deg_v > 0, nxt, v)  # dead end: stay
         return (v, nxt, ids), v
 
     (_, v_last, _), steps = jax.lax.scan(

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.core.graph import PaddedGraph
 from repro.core.walk import run_reference
 from repro.core.walk_distributed import (ShardedGraph, make_distributed_walk)
@@ -166,52 +167,54 @@ class WalkEngine:
     def _dispatch(self, starts, seed: int, walker_ids):
         """Launch one run asynchronously; returns
         (walks, drops, slice_to, update_meta)."""
-        key = jax.random.PRNGKey(seed)
-        if self.plan.backend in ("reference", "fused"):
-            if starts is None:
-                starts = np.arange(self.pg.n, dtype=np.int32)
-            starts = jnp.asarray(starts, jnp.int32)
-            walker_ids = starts if walker_ids is None else \
-                jnp.asarray(walker_ids, jnp.int32)
-            walks = run_reference(self.pg, starts, walker_ids, key,
-                                  self._sampler, self.plan.length)
-            return walks, None, None, self._update_meta()
+        with obs.span("walk.dispatch"):
+            key = jax.random.PRNGKey(seed)
+            if self.plan.backend in ("reference", "fused"):
+                if starts is None:
+                    starts = np.arange(self.pg.n, dtype=np.int32)
+                starts = jnp.asarray(starts, jnp.int32)
+                walker_ids = starts if walker_ids is None else \
+                    jnp.asarray(walker_ids, jnp.int32)
+                walks = run_reference(self.pg, starts, walker_ids, key,
+                                      self._sampler, self.plan.length)
+                return walks, None, None, self._update_meta()
 
-        if self._abstract():
-            raise ValueError("engine was built from an abstract ShardedGraph"
-                             " — only analyze() is available")
-        slice_to = None
-        if starts is None:
-            starts = np.arange(self.sg.n, dtype=np.int32)
-            slice_to = self.sg.n_orig   # padding vertices walk self-loops
-        starts = np.asarray(starts, np.int32)
-        if starts.shape[0] % self.sg.num_shards:
-            raise ValueError(
-                f"walker count {starts.shape[0]} must divide evenly over "
-                f"{self.sg.num_shards} shards")
-        # walkers are co-located with their start vertex: walker block s gets
-        # starts[s*W:(s+1)*W] and reads the start row locally, so each start
-        # must live on the shard its position lands on (else the first step
-        # would silently clamp to a wrong local row).
-        w_local = starts.shape[0] // self.sg.num_shards
-        owner = starts // self.sg.n_local
-        placed = np.arange(starts.shape[0]) // w_local
-        if not np.array_equal(owner, placed):
-            bad = int(np.nonzero(owner != placed)[0][0])
-            raise ValueError(
-                f"starts must be grouped by owning shard (vertex id // "
-                f"{self.sg.n_local}): starts[{bad}]={int(starts[bad])} "
-                f"belongs to shard {int(owner[bad])} but is placed on shard "
-                f"{int(placed[bad])}")
-        walker_ids = starts if walker_ids is None else \
-            np.asarray(walker_ids, np.int32)
-        walks, drops = self._fn(*self._sharded_args(
-            jnp.asarray(starts), jnp.asarray(walker_ids), key))
-        return walks, drops, slice_to, self._update_meta()
+            if self._abstract():
+                raise ValueError("engine was built from an abstract "
+                                 "ShardedGraph — only analyze() is available")
+            slice_to = None
+            if starts is None:
+                starts = np.arange(self.sg.n, dtype=np.int32)
+                slice_to = self.sg.n_orig   # padding vertices walk self-loops
+            starts = np.asarray(starts, np.int32)
+            if starts.shape[0] % self.sg.num_shards:
+                raise ValueError(
+                    f"walker count {starts.shape[0]} must divide evenly over "
+                    f"{self.sg.num_shards} shards")
+            # walkers are co-located with their start vertex: walker block s
+            # gets starts[s*W:(s+1)*W] and reads the start row locally, so
+            # each start must live on the shard its position lands on (else
+            # the first step would silently clamp to a wrong local row).
+            w_local = starts.shape[0] // self.sg.num_shards
+            owner = starts // self.sg.n_local
+            placed = np.arange(starts.shape[0]) // w_local
+            if not np.array_equal(owner, placed):
+                bad = int(np.nonzero(owner != placed)[0][0])
+                raise ValueError(
+                    f"starts must be grouped by owning shard (vertex id // "
+                    f"{self.sg.n_local}): starts[{bad}]={int(starts[bad])} "
+                    f"belongs to shard {int(owner[bad])} but is placed on "
+                    f"shard {int(placed[bad])}")
+            walker_ids = starts if walker_ids is None else \
+                np.asarray(walker_ids, np.int32)
+            walks, drops = self._fn(*self._sharded_args(
+                jnp.asarray(starts), jnp.asarray(walker_ids), key))
+            return walks, drops, slice_to, self._update_meta()
 
     def _finalize(self, dispatched) -> WalkResult:
         walks, drops, slice_to, update_meta = dispatched
-        walks = np.asarray(walks)
+        with obs.span("walk.fetch"):
+            walks = np.asarray(walks)
         if slice_to is not None:
             walks = walks[:slice_to]
         dropped = int(drops) if drops is not None else 0

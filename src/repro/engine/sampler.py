@@ -94,10 +94,12 @@ def exact_slots(cand_ids: jnp.ndarray, cand_w: jnp.ndarray, u: jnp.ndarray,
     prev_rows [W, Dp] (sorted N(u)), rand [W] uniforms in [0, 1).
     Returns the sampled candidate slot per walker, [W] i32.
     """
-    probs = jax.vmap(
-        lambda ci, cw, uu, pr: unnormalized_probs(ci, cw, uu, pr, p, q))(
-            cand_ids, cand_w, u, prev_rows)
-    return draw_slots(probs, cand_ids != PAD_ID, rand[:, None])[:, 0]
+    with jax.named_scope("walk.probs"):
+        probs = jax.vmap(
+            lambda ci, cw, uu, pr: unnormalized_probs(ci, cw, uu, pr, p, q))(
+                cand_ids, cand_w, u, prev_rows)
+    with jax.named_scope("walk.draw"):
+        return draw_slots(probs, cand_ids != PAD_ID, rand[:, None])[:, 0]
 
 
 def first_order_slots(keys: jax.Array, alias_p: jnp.ndarray,
@@ -164,25 +166,28 @@ class Sampler:
     def exact(self, rand, cand_ids, cand_w, u, prev_rows) -> jnp.ndarray:
         if self.fused:
             from repro.kernels.ops import node2vec_step_op
-            return node2vec_step_op(cand_ids, cand_w, u, prev_rows, rand,
-                                    self.p, self.q)
+            with jax.named_scope("walk.probs"):
+                return node2vec_step_op(cand_ids, cand_w, u, prev_rows, rand,
+                                        self.p, self.q)
         return exact_slots(cand_ids, cand_w, u, prev_rows, rand, self.p,
                            self.q)
 
     def choose(self, keys, cand_ids, cand_w, u, prev_rows,
                hot: Optional[HotContext] = None) -> StepChoice:
         """One superstep draw for a [W]-batch of walkers."""
-        k_exact, k_approx = split_keys(keys)
-        rand = jax.vmap(jax.random.uniform)(k_exact)
+        with jax.named_scope("walk.rng"):
+            k_exact, k_approx = split_keys(keys)
+            rand = jax.vmap(jax.random.uniform)(k_exact)
         slot_exact = self.exact(rand, cand_ids, cand_w, u, prev_rows)
         if self.mode == "exact" or hot is None:
             return StepChoice(slot_exact)
-        slot_alias = first_order_slots(k_approx, hot.alias_p, hot.alias_i,
-                                       hot.alias_deg)
-        if self.mode == "approx":
-            gap = approx_gap(hot.deg_u, hot.deg_v, hot.w_min_v, hot.w_max_v,
-                             self.p, self.q)
-            use = hot.is_hot_v & (~hot.is_hot_u) & (gap < self.eps)
-        else:  # approx_always — beyond-paper O(1) path at EVERY hot vertex
-            use = hot.is_hot_v
+        with jax.named_scope("walk.draw"):
+            slot_alias = first_order_slots(k_approx, hot.alias_p,
+                                           hot.alias_i, hot.alias_deg)
+            if self.mode == "approx":
+                gap = approx_gap(hot.deg_u, hot.deg_v, hot.w_min_v,
+                                 hot.w_max_v, self.p, self.q)
+                use = hot.is_hot_v & (~hot.is_hot_u) & (gap < self.eps)
+            else:  # approx_always — beyond-paper O(1) path, every hot v
+                use = hot.is_hot_v
         return StepChoice(slot_exact, slot_alias, use)

@@ -85,9 +85,8 @@ def run_node2vec(args):
               f"{ts.pairs} pairs in {ts.wall_seconds:.1f}s "
               f"({ts.pairs_per_sec:.0f} pairs/s, "
               f"{ts.tokens_per_sec:.0f} tokens/s)")
-        print(f"overlap: walk_wait {ts.walk_wait_seconds:.2f}s, "
-              f"efficiency {ts.overlap_efficiency:.2f}; "
-              f"h2d {ts.h2d_bytes} B vs {ts.h2d_bytes_concat} B staged")
+        print(f"walk_wait {ts.walk_wait_seconds:.2f}s, "
+              f"h2d {ts.h2d_bytes} B")
         if ts.shards > 1:
             print(f"shards: {ts.shards} table shards, "
                   f"collective {ts.collective_bytes} B "

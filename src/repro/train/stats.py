@@ -4,9 +4,9 @@
 
 The walk engine reports what one *run* did and the serving layer what a
 *traffic window* did; the trainer reports what one *streamed training run*
-did: throughput (pairs/sec, tokens/sec), how much walk time hid behind
-training (overlap efficiency), and how many bytes crossed the host→device
-boundary versus what the per-batch host-staging path would have uploaded.
+did: throughput (pairs/sec, tokens/sec), how long it waited on the walk
+source, and how many bytes crossed the host→device boundary. Where each
+round's host time goes is in the profiler trace (``repro.obs`` spans).
 
 ``TrainRecorder`` is the mutable accumulator the trainer feeds per round;
 :meth:`TrainRecorder.snapshot` freezes it into a :class:`TrainStats`.
@@ -33,22 +33,10 @@ class TrainStats:
     ``train_seconds``      — host time driving/finalizing training steps.
     ``wall_seconds``       — end-to-end duration of :meth:`~repro.train.
                              StreamingSGNSTrainer.train`.
-    ``overlap_efficiency`` — estimated fraction of post-round-0 walk time
-                             hidden behind training: round 0 is always fully
-                             exposed (nothing to overlap with), so its wait
-                             estimates the per-round walk cost c, and
-                             efficiency = 1 − Σ wait[1:] / (c·(R−1)),
-                             clipped to [0, 1]; 0.0 when R < 2. An estimate
-                             (load noise moves c), reported for telemetry —
-                             benches gate on the stream/concat wall-clock
-                             ratio instead.
     ``pairs_per_sec`` / ``tokens_per_sec`` — throughput over wall time.
     ``h2d_bytes``          — actual host→device uploads: each round's walks
-                             once, plus the per-round alias refresh.
-    ``h2d_bytes_concat``   — what per-step host batch staging (the old
-                             ``walks_to_sgns_batches`` path) would have
-                             uploaded for the same steps: exact, so the
-                             stream/concat H2D ratio is deterministic.
+                             once, plus the per-round alias refresh (the
+                             ``bytes`` of each ``train.upload`` span).
     ``shards``             — table shards (1 = dense single-device tables).
     ``collective_bytes``   — analytic per-device bytes the sparse row
                              gathers/updates moved across the mesh
@@ -72,11 +60,9 @@ class TrainStats:
     walk_wait_seconds: float = 0.0
     train_seconds: float = 0.0
     wall_seconds: float = 0.0
-    overlap_efficiency: float = 0.0
     pairs_per_sec: float = 0.0
     tokens_per_sec: float = 0.0
     h2d_bytes: int = 0
-    h2d_bytes_concat: int = 0
     shards: int = 1
     collective_bytes: int = 0
     exposed_collective_bytes: int = 0
@@ -96,7 +82,6 @@ class TrainRecorder:
         self.pairs = 0
         self.tokens = 0
         self.h2d_bytes = 0
-        self.h2d_bytes_concat = 0
         self.collective_bytes = 0
         self.exposed_collective_bytes = 0
 
@@ -105,7 +90,7 @@ class TrainRecorder:
         self._waits.append(seconds)
 
     def round_trained(self, seconds: float, steps: int, pairs: int,
-                      tokens: int, h2d_bytes: int, h2d_bytes_concat: int,
+                      tokens: int, h2d_bytes: int,
                       collective_bytes: int = 0,
                       exposed_collective_bytes: int | None = None) -> None:
         self._train_s += seconds
@@ -114,7 +99,6 @@ class TrainRecorder:
         self.pairs += pairs
         self.tokens += tokens
         self.h2d_bytes += h2d_bytes
-        self.h2d_bytes_concat += h2d_bytes_concat
         self.collective_bytes += collective_bytes
         # barrier-style sparse gathers: exposed == total unless told better
         self.exposed_collective_bytes += (
@@ -127,16 +111,6 @@ class TrainRecorder:
         self._train_s += seconds
 
     # ---------------------------------------------------------- snapshot --
-    def overlap_efficiency(self) -> float:
-        if len(self._waits) < 2:
-            return 0.0
-        per_round = self._waits[0]
-        if per_round <= 0.0:
-            return 0.0
-        exposed = sum(self._waits[1:])
-        eff = 1.0 - exposed / (per_round * (len(self._waits) - 1))
-        return min(max(eff, 0.0), 1.0)
-
     def snapshot(self, wall_seconds: float) -> TrainStats:
         wall = max(wall_seconds, 1e-12)
         return TrainStats(
@@ -148,11 +122,9 @@ class TrainRecorder:
             walk_wait_seconds=sum(self._waits),
             train_seconds=self._train_s,
             wall_seconds=wall_seconds,
-            overlap_efficiency=self.overlap_efficiency(),
             pairs_per_sec=self.pairs / wall,
             tokens_per_sec=self.tokens / wall,
             h2d_bytes=self.h2d_bytes,
-            h2d_bytes_concat=self.h2d_bytes_concat,
             shards=self.shards,
             collective_bytes=self.collective_bytes,
             exposed_collective_bytes=self.exposed_collective_bytes,

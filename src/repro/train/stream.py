@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.alias import build_alias
 from repro.core.skipgram import (SGNSConfig, init_params, normalize_embeddings,
                                  sgns_grads)
@@ -50,16 +51,18 @@ from repro.train.stats import TrainRecorder, TrainStats
 @functools.partial(jax.jit, static_argnames=("window",))
 def _gen_pairs(walks, window):
     """Resident-walks -> pair arrays + per-pair validity + valid count."""
-    c, x, valid = device_pairs(walks, window)
-    return c, x, valid, jnp.sum(valid)
+    with jax.named_scope("sgns.pairs"):
+        c, x, valid = device_pairs(walks, window)
+        return c, x, valid, jnp.sum(valid)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "steps", "batch"))
 def _perm_batches(key, n, steps, batch):
     """Device shuffle of ``n`` pair slots, padded to the fixed step grid and
     reshaped [steps, batch] (pad slots are masked by position in the step)."""
-    perm = jax.random.permutation(key, n)
-    return jnp.pad(perm, (0, steps * batch - n)).reshape(steps, batch)
+    with jax.named_scope("sgns.pairs"):
+        perm = jax.random.permutation(key, n)
+        return jnp.pad(perm, (0, steps * batch - n)).reshape(steps, batch)
 
 
 @functools.partial(jax.jit,
@@ -76,21 +79,27 @@ def _train_epoch(params, opt_state, c, x, valid, perm2d, prob, alias, key,
 
     def body(carry, s):
         params, opt_state = carry
-        idx = perm2d[s]
-        in_bounds = (s * batch_size + jnp.arange(batch_size)) < n_pairs
-        batch = {
-            "center": c[idx],
-            "pos": x[idx],
-            "neg": device_negatives(jax.random.fold_in(key, s), prob, alias,
-                                    (batch_size, negatives)),
-            "valid": (valid[idx] & in_bounds).astype(jnp.float32),
-        }
-        loss, grads = sgns_grads(params, batch, backend)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return (apply_updates(params, updates), opt_state), loss
+        with jax.named_scope("sgns.pairs"):
+            idx = perm2d[s]
+            in_bounds = (s * batch_size + jnp.arange(batch_size)) < n_pairs
+            batch = {"center": c[idx], "pos": x[idx]}
+        with jax.named_scope("sgns.negatives"):
+            batch["neg"] = device_negatives(jax.random.fold_in(key, s), prob,
+                                            alias, (batch_size, negatives))
+        with jax.named_scope("sgns.pairs"):
+            batch["valid"] = (valid[idx] & in_bounds).astype(jnp.float32)
+        with jax.named_scope("sgns.grads"):
+            loss, grads = sgns_grads(params, batch, backend)
+        with jax.named_scope("sgns.optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
+        return (params, opt_state), loss
 
-    (params, opt_state), losses = jax.lax.scan(
-        body, (params, opt_state), jnp.arange(perm2d.shape[0]))
+    # the zero tables of the dense gradients are constants of the loop body,
+    # lowered outside every scope inside it: the outer scope names them
+    with jax.named_scope("sgns.grads"):
+        (params, opt_state), losses = jax.lax.scan(
+            body, (params, opt_state), jnp.arange(perm2d.shape[0]))
     return params, opt_state, losses
 
 
@@ -167,14 +176,15 @@ class StreamingSGNSTrainer:
     # ---------------------------------------------------------- one round --
     def _alias_refresh(self, walks: np.ndarray):
         """Fold the round into the cumulative unigram counts and rebuild the
-        [V] negative-sampling alias table (O(V) host, uploaded once)."""
-        self._counts += np.bincount(walks.reshape(-1), minlength=self.vocab)
-        freq = self._counts ** self.power
-        if freq.sum() == 0:
-            freq = np.ones(self.vocab)
-        prob_np, alias_np = build_alias(freq)
-        return jnp.asarray(prob_np), jnp.asarray(alias_np), \
-            prob_np.nbytes + alias_np.nbytes
+        [V] negative-sampling alias table (O(V) host); returns the host
+        (prob, alias)."""
+        with obs.span("train.alias_refresh"):
+            self._counts += np.bincount(walks.reshape(-1),
+                                        minlength=self.vocab)
+            freq = self._counts ** self.power
+            if freq.sum() == 0:
+                freq = np.ones(self.vocab)
+            return build_alias(freq)
 
     def consume(self, walks: np.ndarray) -> None:
         """Train one epoch pass (``epochs`` sub-passes) over one round."""
@@ -182,47 +192,49 @@ class StreamingSGNSTrainer:
         walks = np.ascontiguousarray(walks, np.int32)  # host-ok: round input
         w, l = walks.shape
         n_pairs = num_pairs(w, l, self.window)
-        prob, alias, alias_bytes = self._alias_refresh(walks)
+        prob_np, alias_np = self._alias_refresh(walks)
         if n_pairs == 0:
             self._round += 1
             self.recorder.round_trained(time.perf_counter() - t0, 0, 0,
-                                        w * l, walks.nbytes + alias_bytes, 0)
+                                        w * l, 0)
             return
-        dev_walks = jnp.asarray(walks)
-        c, x, valid, n_valid = _gen_pairs(dev_walks, self.window)
-        self._pair_counts.append(n_valid * self.epochs)
-        steps = math.ceil(n_pairs / self.batch_size)
-        rkey = jax.random.fold_in(self._key, self._round)
-        for e in range(self.epochs):
-            pkey, skey = jax.random.split(jax.random.fold_in(rkey, e))
-            perm2d = _perm_batches(pkey, n_pairs, steps, self.batch_size)
-            if self.shard_tables:
-                self.params, self.opt_state, losses = train_epoch_sharded(
-                    self.params, self.opt_state, c, x, valid, perm2d,
-                    prob, alias, skey,
-                    mesh=self.mesh, opt=self._opt,
-                    negatives=self.negatives, backend=self.sgns_backend,
-                    n_pairs=n_pairs, u_in=self._u_in, u_out=self._u_out)
-            else:
-                self.params, self.opt_state, losses = _train_epoch(
-                    self.params, self.opt_state, c, x, valid, perm2d,
-                    prob, alias, skey,
-                    opt=self._opt, negatives=self.negatives,
-                    backend=self.sgns_backend, n_pairs=n_pairs)
-            if self.record_loss:
-                self._losses.append(losses)
+        h2d = walks.nbytes + prob_np.nbytes + alias_np.nbytes
+        with obs.span("train.upload", bytes=h2d):
+            dev_walks, prob, alias = (jnp.asarray(a) for a in
+                                      (walks, prob_np, alias_np))
+        with obs.span("train.dispatch"):
+            c, x, valid, n_valid = _gen_pairs(dev_walks, self.window)
+            self._pair_counts.append(n_valid * self.epochs)
+            steps = math.ceil(n_pairs / self.batch_size)
+            rkey = jax.random.fold_in(self._key, self._round)
+            for e in range(self.epochs):
+                pkey, skey = jax.random.split(jax.random.fold_in(rkey, e))
+                perm2d = _perm_batches(pkey, n_pairs, steps, self.batch_size)
+                if self.shard_tables:
+                    self.params, self.opt_state, losses = \
+                        train_epoch_sharded(
+                            self.params, self.opt_state, c, x, valid, perm2d,
+                            prob, alias, skey,
+                            mesh=self.mesh, opt=self._opt,
+                            negatives=self.negatives,
+                            backend=self.sgns_backend, n_pairs=n_pairs,
+                            u_in=self._u_in, u_out=self._u_out)
+                else:
+                    self.params, self.opt_state, losses = _train_epoch(
+                        self.params, self.opt_state, c, x, valid, perm2d,
+                        prob, alias, skey,
+                        opt=self._opt, negatives=self.negatives,
+                        backend=self.sgns_backend, n_pairs=n_pairs)
+                if self.record_loss:
+                    self._losses.append(losses)
         self._round += 1
-        # concat-equivalent H2D: the host path stages center/pos/neg (i32)
-        # + valid (f32) per step — deterministic, so the ratio metric is exact
-        per_step = 4 * self.batch_size * (3 + self.negatives)
         coll = 0
         if self.shard_tables:
             from repro.roofline.traffic import sgns_exchange_bytes
             coll = steps * self.epochs * sgns_exchange_bytes(
                 self._u_in + self._u_out, self.dim, self.shards)
         self.recorder.round_trained(
-            time.perf_counter() - t0, steps * self.epochs, 0, w * l,
-            walks.nbytes + alias_bytes, steps * self.epochs * per_step,
+            time.perf_counter() - t0, steps * self.epochs, 0, w * l, h2d,
             collective_bytes=coll)
 
     # ------------------------------------------------------------- driver --

@@ -170,19 +170,14 @@ def test_train_stats_accounting(tiny_graph):
     _, st = trainer.train(iter(rounds))
 
     want_pairs, want_steps, want_h2d = 0, 0, 0
-    per_step = 4 * cfg.batch_size * (3 + cfg.negatives)
-    want_h2d_concat = 0
     for w in rounds:
         hc, _ = sgns_pairs(w, cfg.window)
         want_pairs += len(hc) * cfg.epochs
         steps = -(-num_pairs(*w.shape, cfg.window) // cfg.batch_size)
         want_steps += steps * cfg.epochs
         want_h2d += w.astype(np.int32).nbytes + tiny_graph.n * 8
-        want_h2d_concat += steps * cfg.epochs * per_step
     assert st.pairs == want_pairs
     assert st.steps == want_steps
     assert st.h2d_bytes == want_h2d
-    assert st.h2d_bytes_concat == want_h2d_concat
     assert st.tokens == sum(w.size for w in rounds)
-    assert 0.0 <= st.overlap_efficiency <= 1.0
     assert st.pairs_per_sec > 0 and st.wall_seconds > 0

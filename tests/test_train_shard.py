@@ -193,7 +193,7 @@ def test_alias_tables_match_global_sampler(tiny_graph):
     rounds = _rounds(tiny_graph.n, n=2)
     for r in rounds:
         tr.consume(r)
-    prob, alias, _ = tr._alias_refresh(np.zeros((0, 2), np.int32))
+    prob, alias = tr._alias_refresh(np.zeros((0, 2), np.int32))
     ref = NegativeSampler(np.concatenate(rounds, axis=0), tiny_graph.n)
     np.testing.assert_allclose(np.asarray(prob), ref.prob, rtol=0,
                                atol=1e-12)
