@@ -4,8 +4,8 @@ Two representations:
 
 * :class:`CSRGraph` — host-side (numpy) compressed-sparse-row graph. This is
   the build/IO format: edge lists come in, get symmetrized/deduped, and the
-  per-row neighbor lists are **sorted ascending** (membership tests during the
-  2nd-order walk are binary searches).
+  per-row neighbor lists are **sorted ascending**, so every layout orders a
+  vertex's slots alike and delta batches can slice rows by ``searchsorted``.
 
 * :class:`PaddedGraph` — device-side (jnp) degree-capped padded adjacency plus
   a replicated **hot cache** holding the full rows of popular vertices. This is
@@ -15,8 +15,9 @@ Two representations:
   neighbor list never crosses ICI (paper §3.4, FN-Cache).
 
 Pad convention: neighbor ids are padded with ``PAD_ID`` (i32 max) so rows stay
-sorted-ascending (pads sort last) and ``searchsorted`` membership remains
-correct; weights are padded with 0 so padded lanes carry zero probability.
+sorted-ascending (pads sort last) and the membership test can tell pad
+candidates apart; weights are padded with 0 so padded lanes carry zero
+probability.
 """
 from __future__ import annotations
 

@@ -10,8 +10,11 @@ The walk moved u -> v; for every candidate x in N(v):
 Nothing is ever precomputed or stored per (u, v) pair — this is the paper's
 central memory-saving idea (Eq. 1: storing all pairs costs 8*sum(d_i^2) bytes).
 
-Membership x in N(u) is a binary search against the *sorted* neighbor row of u
-(pads are PAD_ID = i32 max, so they sort last and never match).
+Membership x in N(u) compares every candidate with every lane of u's neighbor
+row and ORs the hits: one compare-and-reduce that XLA fuses into a single
+reduce, with no gathers and no [D, Dp] temporary. It does not need the row
+sorted. Pad candidates (PAD_ID = i32 max) are masked out, so a pad never
+counts as a hit.
 
 ``approx_gap`` implements the FN-Approx bounds (paper Eq. 2-3), generalized to
 any (p, q) ordering (the paper assumes 1/p <= 1 <= 1/q).
@@ -27,14 +30,12 @@ from repro.core.graph import PAD_ID, CSRGraph
 
 
 def membership(prev_sorted: jnp.ndarray, cand_ids: jnp.ndarray) -> jnp.ndarray:
-    """For each candidate id, is it present in the sorted row ``prev_sorted``?
+    """For each candidate id, is it present in the row ``prev_sorted``?
 
-    prev_sorted: [Dp] i32 (ascending, PAD_ID padded); cand_ids: [D] i32.
+    prev_sorted: [..., Dp] i32 (PAD_ID padded); cand_ids: [..., D] i32.
+    Returns [..., D] bool; a PAD_ID candidate is never a hit.
     """
-    dp = prev_sorted.shape[-1]
-    pos = jnp.searchsorted(prev_sorted, cand_ids)
-    pos = jnp.minimum(pos, dp - 1)
-    hit = prev_sorted[pos] == cand_ids
+    hit = jnp.any(cand_ids[..., :, None] == prev_sorted[..., None, :], axis=-1)
     return hit & (cand_ids != PAD_ID)
 
 

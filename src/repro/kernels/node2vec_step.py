@@ -7,9 +7,11 @@ One kernel fuses the per-walker hot loop of the walk engine:
     probs       alpha * w        (VPU)
     sampling    inverse-CDF      (shared prefix sum + compare-count)
 
-The unfused jnp path materializes membership, alpha, probs and the prefix sum
-as separate HBM tensors ([W, D] each); fusing keeps everything for a walker
-block resident in VMEM, so the step reads the candidate/prev rows once.
+The unfused jnp path leaves fusion to XLA: its membership compare fuses into
+one reduce with no [W, D, Dp] temporary, but alpha, probs and the prefix
+sum can still land in HBM as [W, D] tensors between fusions. The kernel keeps
+everything for a walker block resident in VMEM, so the step reads the
+candidate/prev rows once.
 
 The draw itself is :func:`repro.engine.sampler.draw_slots` — the same
 function the jnp path calls, with ``pltpu.roll`` as its lane shift — so the

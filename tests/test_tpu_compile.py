@@ -15,6 +15,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core.walk_distributed import ShardedGraph
 from repro.engine import WalkEngine, WalkPlan
+from repro.engine.sampler import exact_slots
 from repro.kernels import node2vec_step as step_kernel
 from repro.kernels import ops
 from repro.kernels import sgns as sgns_kernel
@@ -70,6 +71,22 @@ def test_node2vec_step_kernel_compiles_for_v5e(sds, width):
     ).lower(ids, sds((w, width), jnp.float32), col(jnp.int32), ids,
             col(jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("w,width", [(32768, 28), (1024, 5945)])
+def test_exact_slots_membership_stays_fused_for_v5e(sds, w, width):
+    """The jnp exact draw at the ER-20 walk cell's shape and at a Skew-S
+    hub's row width: the membership compare of every candidate against
+    every prev lane must stay inside one reduce fusion, so the compiled
+    program never holds a [W, D, Dp] temporary, nor even one [W, D] i32
+    buffer."""
+    ids = sds((w, width), jnp.int32)
+    col = lambda dt: sds((w,), dt)
+    compiled = jax.jit(
+        lambda c, cw, u, pr, r: exact_slots(c, cw, u, pr, r, 1.0, 0.5)
+    ).lower(ids, sds((w, width), jnp.float32), col(jnp.int32), ids,
+            col(jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < w * width * 4
 
 
 def test_sgns_kernel_compiles_for_v5e(sds):

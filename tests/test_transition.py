@@ -1,5 +1,6 @@
 """2nd-order transition probabilities vs python-set oracle + FN-Approx
 bound correctness (paper Eq. 2-3)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -51,6 +52,42 @@ def test_membership_with_pads():
     cand = jnp.asarray([1, 2, 9, 10, PAD_ID], jnp.int32)
     got = np.asarray(membership(prev, cand))
     assert list(got) == [False, True, True, False, False]
+
+
+def _sorted_rows(rng, rows, width, id_range):
+    """``rows`` ascending rows of distinct ids in [0, id_range), each with a
+    random number of live lanes and a PAD_ID tail."""
+    out = np.full((rows, width), PAD_ID, np.int32)
+    for r in range(rows):
+        live = int(rng.integers(0, min(width, id_range) + 1))
+        out[r, :live] = np.sort(rng.choice(id_range, live, replace=False))
+    return out
+
+
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("d,dp,case", [
+    (8, 8, "random"), (5, 13, "random"), (13, 5, "random"),
+    (28, 28, "random"), (40, 129, "random"), (6, 9, "all_pads"),
+])
+def test_membership_matches_set_oracle(d, dp, case, batched):
+    rng = np.random.default_rng(d * 1000 + dp)
+    rows = 16
+    id_range = max(d, dp) + 8       # small id range: many common neighbors
+    cand = _sorted_rows(rng, rows, d, id_range)
+    prev = _sorted_rows(rng, rows, dp, id_range)
+    if case == "all_pads":
+        cand[:] = PAD_ID
+    if batched:
+        got = np.asarray(jax.vmap(membership)(jnp.asarray(prev),
+                                              jnp.asarray(cand)))
+    else:
+        got = np.stack([np.asarray(membership(jnp.asarray(p_row),
+                                              jnp.asarray(c_row)))
+                        for p_row, c_row in zip(prev, cand)])
+    want = np.array([[int(x) != PAD_ID and int(x) in set(p_row.tolist())
+                      for x in c_row] for p_row, c_row in zip(prev, cand)])
+    assert got.shape == (rows, d) and got.dtype == np.bool_
+    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("n,m,seed", [
